@@ -32,15 +32,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:                                    # jax >= 0.6 top-level export
-    from jax import shard_map
-except ImportError:                     # jax 0.4.x/0.5.x: experimental home,
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        # ...where today's ``check_vma`` kwarg was called ``check_rep``
-        return _shard_map_04(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma)
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -177,10 +169,7 @@ def _head_loss(cfg, params, y, tok_mb, g: _Geom, cdt):
 
 
 def _pvary(x, axes):
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x      # old jax: no varying-axis tracking; pcast is a no-op
-    return jax.tree.map(lambda a: pcast(a, axes, to="varying"), x)
+    return jax.tree.map(lambda a: jax.lax.pcast(a, axes, to="varying"), x)
 
 
 def _butterfly_psum(x, S: int, R: int, msize: int):

@@ -59,14 +59,15 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         s = jnp.where(live, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        m_safe = jnp.where(jnp.isinf(m_new), 0.0, m_new)
-        p = jnp.exp(s - m_safe[:, None])
-        p = jnp.where(jnp.isinf(m_new)[:, None], 0.0, p)
-        corr = jnp.where(jnp.isinf(m_prev), 0.0, jnp.exp(m_prev - m_safe))
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        # m starts at -inf and every score is >= NEG_INF, so m_new is
+        # finite and corr = exp(-inf) = 0 on the first computed tile; a
+        # fully masked tile is wiped by the next live tile's corr
+        m_prev = m_ref[...]                            # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())))
         m_ref[...] = m_new
 
@@ -82,7 +83,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     def finish():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, ...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0, ...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -130,8 +131,8 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, qt.shape[2], d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),      # m
-            pltpu.VMEM((block_q,), jnp.float32),      # l
+            pltpu.VMEM((block_q, 1), jnp.float32),    # m
+            pltpu.VMEM((block_q, 1), jnp.float32),    # l
             pltpu.VMEM((block_q, d), jnp.float32),    # acc
         ],
         interpret=interpret,

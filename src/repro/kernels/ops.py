@@ -1,8 +1,10 @@
 """Jit'd public entry points for the Pallas kernels.
 
-Routing: on TPU the kernels run compiled; anywhere else (this CPU container)
-they run in ``interpret=True`` mode — same kernel body, Python-evaluated —
-so correctness is exercised everywhere the framework runs.
+Routing: on TPU the kernels run compiled; on the CPU (the test suite) they
+run in ``interpret=True`` mode — same kernel body, evaluated by XLA:CPU —
+so correctness is exercised without a chip.  Any other backend is an
+error: a kernel never silently gives way to interpret mode or to the jnp
+reference.
 
 Gradients: ``flash_attention`` carries a custom VJP whose backward is the
 AD of the blockwise oracle under remat (recompute-based flash backward).
@@ -27,7 +29,14 @@ from repro.kernels.rwkv6_scan import rwkv6_chunked_fwd
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on tpu or interpreted on cpu; the "
+        f"default backend is {backend!r}")
 
 
 # ---------------------------------------------------------------------------

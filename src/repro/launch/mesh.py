@@ -13,24 +13,19 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
-def compat_make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer jax; older releases use
-    plain Auto axes implicitly."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with every axis Auto: the step builders annotate
+    shardings and leave the rest to GSPMD propagation."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(shape: Tuple[int, ...] = None,
@@ -42,7 +37,7 @@ def make_host_mesh(shape: Tuple[int, ...] = None,
     if shape is None:
         shape = (1, n)
         axes = ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -2,6 +2,13 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch granite-8b \
         --requests 8 --max-new 16 [--tools]
+
+``--reduced`` (the default) serves the smoke-sized config in float32 with
+the jnp attention path, which the CPU runs.  ``--no-reduced`` serves the
+published widths in bf16 through the Pallas kernels, for a TPU; only the
+depth may be cut (``--layers``), and ``--kv-blocks`` gives the engine a
+paged KV pool of that many blocks.  ``chip_smoke.py`` drives the same
+:func:`build_engine` on the chip.
 """
 
 from __future__ import annotations
@@ -9,16 +16,41 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from typing import Optional
 
-import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, RunConfig, get_config, reduced_config
-from repro.models.api import build_model
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models.api import build_model, init_params
 from repro.offload.tools import ToolExecutor
 from repro.offload.vectordb import VectorDB
-from repro.serving.engine import ServeEngine
+from repro.serving.engine import EngineConfig, ServeEngine
 from repro.serving.tool_loop import run_scenario
+
+
+def build_engine(arch: str = "granite-8b", *, reduced: bool = True,
+                 layers: int = 0, max_batch: int = 4, max_len: int = 128,
+                 kv_blocks: Optional[int] = None,
+                 seed: int = 0) -> ServeEngine:
+    """The engine this launcher serves, with seeded random weights.
+
+    ``reduced``: smoke widths, float32, jnp attention.  Otherwise the
+    published widths in bf16 with ``use_kernels``; ``layers`` (0 = the
+    config's own depth) is the only cut."""
+    cfg = get_config(arch)
+    if reduced:
+        cfg = reduced_config(cfg)
+        rcfg = RunConfig(param_dtype="float32", compute_dtype="float32",
+                         remat=False)
+    else:
+        rcfg = RunConfig(param_dtype="bfloat16", compute_dtype="bfloat16",
+                         remat=False, use_kernels=True)
+    if layers:
+        cfg = dataclasses.replace(cfg, n_layers=layers)
+    model = build_model(cfg, rcfg)
+    return ServeEngine(model, init_params(model, seed), max_batch, max_len,
+                       config=EngineConfig(kv_blocks=kv_blocks))
 
 
 def main():
@@ -29,18 +61,21 @@ def main():
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--layers", type=int, default=4)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke widths in float32 (--no-reduced: published "
+                         "widths in bf16 through the Pallas kernels)")
+    ap.add_argument("--kv-blocks", type=int, default=None,
+                    help="serve from a paged KV pool of this many blocks")
     ap.add_argument("--tools", action="store_true",
                     help="run the paper's §4.3 agent scenario instead")
     args = ap.parse_args()
 
-    cfg = reduced_config(get_config(args.arch))
-    if args.layers:
-        cfg = dataclasses.replace(cfg, n_layers=args.layers)
-    rcfg = RunConfig(param_dtype="float32", compute_dtype="float32",
-                     remat=False)
-    model = build_model(cfg, rcfg)
-    params = model.init(jax.random.key(0))
-    engine = ServeEngine(model, params, args.max_batch, args.max_len)
+    enable_compile_cache()
+    engine = build_engine(args.arch, reduced=args.reduced, layers=args.layers,
+                          max_batch=args.max_batch, max_len=args.max_len,
+                          kv_blocks=args.kv_blocks)
+    cfg = engine.model.cfg
 
     if args.tools:
         db = VectorDB(n_docs=20_000, dim=128)

@@ -3,8 +3,9 @@
     PYTHONPATH=src python -m repro.launch.train --arch granite-8b \
         --steps 200 --reduced --schedule hybrid [--strategy pp_shardmap]
 
-``--reduced`` runs the smoke-sized config on local devices (CPU-feasible);
-full configs target the production mesh (real fleet or the dry-run).
+``--reduced`` (the default) runs the smoke-sized config in float32 on local
+devices (CPU-feasible); ``--no-reduced`` keeps the published widths in
+bf16, for the production mesh (real fleet or the dry-run).
 Fault tolerance: checkpoints every --ckpt-every, auto-resume from --ckpt-dir.
 """
 
@@ -19,7 +20,8 @@ import jax.numpy as jnp
 from repro.configs import (ARCH_IDS, RunConfig, ShapeConfig, get_config,
                            reduced_config)
 from repro.data.synthetic import DataConfig, FrontendPipeline, TokenPipeline
-from repro.models.api import build_model
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models.api import build_model, init_params
 from repro.optim import adamw
 from repro.runtime.trainer import Trainer, TrainerConfig
 
@@ -31,9 +33,12 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-3)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke widths in float32 (--no-reduced: published "
+                         "widths in bf16)")
     ap.add_argument("--layers", type=int, default=0,
-                    help="override n_layers on the reduced config")
+                    help="override n_layers")
     ap.add_argument("--schedule", choices=["gpipe", "hybrid"], default="hybrid")
     ap.add_argument("--strategy", default="single",
                     choices=["single", "pp_shardmap", "gspmd_tp", "gspmd_pp"])
@@ -42,12 +47,14 @@ def main():
     ap.add_argument("--use-kernels", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_config(cfg)
     if args.layers:
         cfg = dataclasses.replace(cfg, n_layers=args.layers)
-    rcfg = RunConfig(param_dtype="float32", compute_dtype="float32",
+    dtype = "float32" if args.reduced else "bfloat16"
+    rcfg = RunConfig(param_dtype=dtype, compute_dtype=dtype,
                      remat=False, schedule=args.schedule,
                      use_kernels=args.use_kernels)
     model = build_model(cfg, rcfg)
@@ -68,7 +75,7 @@ def main():
             return p2, o2, dict(loss=loss, **st)
 
         def init_state():
-            p = model.init(jax.random.key(0))
+            p = init_params(model)
             return p, adamw.init(p)
     else:
         from repro.launch.mesh import make_host_mesh
@@ -85,7 +92,7 @@ def main():
             return jitted(params, opt, batch)
 
         def init_state():
-            p = model.init(jax.random.key(0))
+            p = init_params(model)
             if "to_pipeline" in built:
                 p = built["to_pipeline"](p)
             return p, adamw.init(p)

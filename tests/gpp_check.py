@@ -24,8 +24,8 @@ def check(arch):
     shape = ShapeConfig("t", seq_len=seq, global_batch=8, kind="train")
     rcfg = RunConfig(param_dtype="float32", compute_dtype="float32",
                      remat=False, microbatches=4)
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     oc = adamw.AdamWConfig(lr=1e-2, warmup_steps=0, schedule="const",
                            weight_decay=0.0)
     built = gpp.make_gspmd_pp_train_step(cfg, shape, rcfg, mesh, oc)

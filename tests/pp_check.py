@@ -32,8 +32,8 @@ def check_arch(arch: str, schedule: str, seed: int = 0) -> float:
                         global_batch=8, kind="train")
     rcfg = RunConfig(param_dtype="float32", compute_dtype="float32",
                      remat=False, schedule=schedule, microbatches=4)
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     opt_cfg = adamw.AdamWConfig(lr=1e-2, warmup_steps=0, schedule="const",
                                 weight_decay=0.0)

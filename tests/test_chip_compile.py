@@ -1,0 +1,130 @@
+"""Compile the main path's kernels and steps for a described TPU v5e.
+
+Nothing runs: the TPU compiler installed with JAX compiles for a chip that
+is described, not attached, and refuses what the chip would refuse (tile
+alignment, vector layouts, VMEM).  Interpret-mode tests cannot show that.
+Shapes are granite-8b's published widths: 32 q heads, 8 KV heads,
+head_dim 128, d_model 4096, bf16.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import RunConfig, get_config
+from repro.kernels import ops
+from repro.kernels.decode_attention import decode_attention_fwd
+from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.paged_attention import paged_decode_attention_fwd
+from repro.models.api import build_model
+
+H, G, D = 32, 8, 128
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without one: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _shape(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_compiles(one_chip):
+    b, t = 1, 512
+    c = _compile(lambda q, k, v: flash_attention_fwd(q, k, v, causal=True),
+                 _shape(one_chip, (b, t, H, D), BF16),
+                 _shape(one_chip, (b, t, G, D), BF16),
+                 _shape(one_chip, (b, t, G, D), BF16))
+    _assert_kernel(c)
+
+
+def test_decode_attention_compiles(one_chip):
+    b, s = 8, 2048
+    c = _compile(lambda q, k, v, m: decode_attention_fwd(q, k, v, m),
+                 _shape(one_chip, (b, 1, H, D), BF16),
+                 _shape(one_chip, (b, s, G, D), BF16),
+                 _shape(one_chip, (b, s, G, D), BF16),
+                 _shape(one_chip, (b, s), jnp.bool_))
+    _assert_kernel(c)
+
+
+def test_paged_decode_attention_compiles(one_chip):
+    b, n_blocks, bs, max_blocks = 8, 1025, 16, 128
+    c = _compile(
+        lambda q, kp, vp, bt, pos: paged_decode_attention_fwd(q, kp, vp, bt,
+                                                              pos),
+        _shape(one_chip, (b, 1, H, D), BF16),
+        _shape(one_chip, (n_blocks, bs, G, D), BF16),
+        _shape(one_chip, (n_blocks, bs, G, D), BF16),
+        _shape(one_chip, (b, max_blocks), jnp.int32),
+        _shape(one_chip, (b,), jnp.int32))
+    _assert_kernel(c)
+
+
+@pytest.fixture
+def granite_2l(one_chip, monkeypatch):
+    """granite-8b at full width, 2 layers, bf16 with kernels; its params
+    as shapes on the described chip.  The CPU backend would route the
+    kernels to interpret mode: this compiles them for the chip instead."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("granite-8b"), n_layers=2)
+    model = build_model(cfg, RunConfig(param_dtype="bfloat16",
+                                       compute_dtype="bfloat16", remat=False,
+                                       use_kernels=True))
+    params = jax.tree.map(
+        lambda a: _shape(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.key(0))))
+    return model, params
+
+
+def test_engine_paged_decode_step_compiles(one_chip, granite_2l):
+    """The jitted step PagedBackend.step dispatches (8 lanes, 2048-token
+    lanes, 1024 blocks of 16)."""
+    model, params = granite_2l
+    ds = model.decode_state
+    cache = jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
+                         jax.eval_shape(lambda: ds.pool_init(8, 1024, 16)))
+    c = jax.jit(ds.pool_step, donate_argnums=1).lower(
+        params, cache, _shape(one_chip, (8, 1), jnp.int32),
+        _shape(one_chip, (8, 2048 // 16), jnp.int32)).compile()
+    _assert_kernel(c)
+
+
+def test_engine_batched_prefill_compiles(one_chip, granite_2l):
+    """The engine's batched bucketed prefill (4 prompts in a 128 bucket)."""
+    model, params = granite_2l
+    prefill = model.decode_state.batched_prefill
+    c = _compile(lambda p, t, n: prefill(p, {"tokens": t}, n, 2048), params,
+                 _shape(one_chip, (4, 128), jnp.int32),
+                 _shape(one_chip, (4,), jnp.int32))
+    _assert_kernel(c)

@@ -15,6 +15,9 @@ ROOT = Path(__file__).resolve().parents[1]
 
 def _run(script_args, timeout=900):
     env = dict(os.environ)
+    # CPU checks on virtual devices: a child must never reach for a chip
+    # (the parent pytest process may hold it)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(ROOT / "src")
     out = subprocess.run([sys.executable] + script_args, cwd=ROOT,

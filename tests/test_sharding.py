@@ -9,10 +9,7 @@ from repro.configs import ARCH_IDS, RunConfig, get_config
 from repro.core import sharding as sh
 from repro.models.api import build_model
 
-try:
-    MESH = AbstractMesh((16, 16), ("data", "model"))        # jax >= 0.6
-except TypeError:
-    MESH = AbstractMesh((("data", 16), ("model", 16)))      # jax 0.4.x
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 
 def _params_shape(arch):
