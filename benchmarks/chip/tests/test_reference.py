@@ -1,0 +1,59 @@
+"""The float32 reference agrees with the program's jnp path
+(``use_kernels=False``, float32) at reduced width: prefill, then decode
+through the cache, teacher-forced."""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import spec
+import tiny
+import weights
+from harness.serve import model_config
+
+
+def program_logits(config, seed, toks, n_prompt):
+    from repro.configs.base import RunConfig
+    from repro.models.api import build_model
+
+    model = build_model(model_config(config),
+                        RunConfig(param_dtype="float32", compute_dtype="float32",
+                                  remat=False, use_kernels=False))
+    params = weights.make(config, seed)
+    lg, cache = model.prefill(params, {"tokens": jnp.asarray(toks[None, :n_prompt])},
+                              len(toks))
+    out = [lg[0]]
+    for t in toks[n_prompt:-1]:
+        lg, cache = model.decode_step(params, cache, jnp.asarray([[t]], jnp.int32))
+        out.append(lg[0])
+    return np.stack([np.asarray(o[:config["vocab_size"]]) for o in out])
+
+
+@pytest.mark.parametrize("seed", [0, 2**33 + 7])
+def test_reference_matches_the_program_jnp_path(seed):
+    config = {**tiny.cell().config, "torch_dtype": "float32"}
+    toks = np.random.default_rng(seed).integers(0, config["vocab_size"], 40,
+                                                dtype=np.int32)
+    n_prompt = 24
+    got = program_logits(config, seed, toks, n_prompt)
+    ref = spec.module("reference", "granite")
+    want = ref.logits(config, seed, toks)[n_prompt - 1:len(toks) - 1]
+    scale = np.abs(want).max()
+    assert scale > 1.0                       # logits are not all near zero
+    np.testing.assert_allclose(got, want, atol=2e-4 * scale, rtol=0)
+
+
+def test_gaps_are_zero_on_the_reference_own_choices():
+    config = {**tiny.cell().config, "torch_dtype": "float32"}
+    ref = spec.module("reference", "granite")
+    rng = np.random.default_rng(5)
+    prompt = rng.integers(0, config["vocab_size"], 20, dtype=np.int32)
+    toks = list(prompt)
+    for _ in range(6):                       # greedy continuation by the reference
+        toks.append(int(np.argmax(ref.logits(config, 5, np.asarray(toks))[-1])))
+    toks = np.asarray(toks, np.int32)
+    served, ctl = ref.logit_gaps(config, 5, [(toks, 20)], pad_to=32,
+                                 control="int8")
+    assert served[0].shape == (6,)
+    assert np.abs(served[0]).max() <= 1e-5
+    assert ctl[0].shape == (6,) and (ctl[0] >= 0).all()
