@@ -126,5 +126,6 @@ def decode_attention_fwd(q: jax.Array, ck: jax.Array, cv: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         scratch_shapes=scratch_shapes(h, d),
         interpret=interpret,
+        name="decode_attention",
     )(q[:, 0], ck.reshape(b, s_pad * g, d), cv.reshape(b, s_pad * g, d), live)
     return out[:, None]

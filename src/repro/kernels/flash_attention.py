@@ -136,5 +136,6 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, d), jnp.float32),    # acc
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return out[:, :, :t].transpose(0, 2, 1, 3)

@@ -111,6 +111,7 @@ def mamba2_ssd_fwd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="mamba2_ssd",
     )(xx, dtt, aa, B, C, dd)
     y = y[:, :t].reshape(bsz, h, t, p).transpose(0, 2, 1, 3)
     return y, s_out.reshape(bsz, h, p, n)

@@ -79,6 +79,7 @@ def paged_decode_attention_fwd(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_tables, pos, q[:, 0], kp.reshape(nb, rows, d),
       vp.reshape(nb, rows, d))
     return out[:, None]

@@ -40,5 +40,6 @@ def rmsnorm_fwd(x: jax.Array, scale: jax.Array, eps: float = 1e-5,
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(xr.shape, x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(xr, scale)
     return out[:n].reshape(orig_shape)

@@ -101,6 +101,7 @@ def rwkv6_chunked_fwd(r: jax.Array, k: jax.Array, v: jax.Array,
         out_shape=jax.ShapeDtypeStruct(rr.shape, r.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dk), jnp.float32)],
         interpret=interpret,
+        name="rwkv6_scan",
     )(rr, kk, vv, ld, uu)
     out = out[:, :t].reshape(b, h, t, dk).transpose(0, 2, 1, 3)
     return out

@@ -209,33 +209,39 @@ def decode_attn_paged(params: dict, cfg: ModelConfig, x: jax.Array,
     nb, bs = kp.shape[0], kp.shape[1]
     span_l = block_tables.shape[1] * bs           # per-lane logical capacity
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    q, k, v = _proj_qkv(params, x, x, cfg)
-    if use_rope:
-        q = apply_rope(q, pos[:, None], cfg.rope_theta)
-        k = apply_rope(k, pos[:, None], cfg.rope_theta)
+    with jax.named_scope("attn"):
+        q, k, v = _proj_qkv(params, x, x, cfg)
+        if use_rope:
+            q = apply_rope(q, pos[:, None], cfg.rope_theta)
+            k = apply_rope(k, pos[:, None], cfg.rope_theta)
     p_eff = jnp.minimum(pos, span_l - 1)          # saturate like the dense path
-    lane = jnp.arange(b)
-    dest = block_tables[lane, p_eff // bs] * bs + p_eff % bs      # (B,) flat
-    kp = kp.reshape((nb * bs,) + kp.shape[2:]).at[dest].set(
-        k[:, 0].astype(kp.dtype)).reshape(kp.shape)
-    vp = vp.reshape((nb * bs,) + vp.shape[2:]).at[dest].set(
-        v[:, 0].astype(vp.dtype)).reshape(vp.shape)
+    with jax.named_scope("kv_write"):
+        lane = jnp.arange(b)
+        dest = block_tables[lane, p_eff // bs] * bs + p_eff % bs   # (B,) flat
+        kp = kp.reshape((nb * bs,) + kp.shape[2:]).at[dest].set(
+            k[:, 0].astype(kp.dtype)).reshape(kp.shape)
+        vp = vp.reshape((nb * bs,) + vp.shape[2:]).at[dest].set(
+            v[:, 0].astype(vp.dtype)).reshape(vp.shape)
     scale = cfg.head_dim ** -0.5
-    if use_kernels:
-        from repro.kernels import ops as kops
-        out = kops.paged_decode_attention(q, kp.astype(q.dtype),
-                                          vp.astype(q.dtype), block_tables,
-                                          p_eff, scale=scale)
-    else:
-        # gather reference: materialise each lane's logical KV view
-        ck = kp[block_tables].reshape(b, span_l, cfg.n_kv_heads, cfg.head_dim)
-        cv = vp[block_tables].reshape(b, span_l, cfg.n_kv_heads, cfg.head_dim)
-        valid = jnp.arange(span_l)[None, :] <= p_eff[:, None]     # (B, span_l)
-        nrep = cfg.n_heads // cfg.n_kv_heads
-        kk = _repeat_kv(ck.astype(q.dtype), nrep)
-        vv = _repeat_kv(cv.astype(q.dtype), nrep)
-        out = sdpa(q, kk, vv, valid[:, None, None, :], scale)
-    return out.reshape(b, 1, cfg.q_dim) @ params["wo"], kp, vp
+    with jax.named_scope("attn"):
+        if use_kernels:
+            from repro.kernels import ops as kops
+            out = kops.paged_decode_attention(q, kp.astype(q.dtype),
+                                              vp.astype(q.dtype), block_tables,
+                                              p_eff, scale=scale)
+        else:
+            # gather reference: materialise each lane's logical KV view
+            ck = kp[block_tables].reshape(b, span_l, cfg.n_kv_heads,
+                                          cfg.head_dim)
+            cv = vp[block_tables].reshape(b, span_l, cfg.n_kv_heads,
+                                          cfg.head_dim)
+            valid = jnp.arange(span_l)[None, :] <= p_eff[:, None]  # (B, span_l)
+            nrep = cfg.n_heads // cfg.n_kv_heads
+            kk = _repeat_kv(ck.astype(q.dtype), nrep)
+            vv = _repeat_kv(cv.astype(q.dtype), nrep)
+            out = sdpa(q, kk, vv, valid[:, None, None, :], scale)
+        out = out.reshape(b, 1, cfg.q_dim) @ params["wo"]
+    return out, kp, vp
 
 
 def prefill_attn(params: dict, cfg: ModelConfig, x: jax.Array,
@@ -246,30 +252,34 @@ def prefill_attn(params: dict, cfg: ModelConfig, x: jax.Array,
 
     Returns (out (B,T,D), ck (B,span,KVH,Dh), cv)."""
     b, t, _ = x.shape
-    q, k, v = _proj_qkv(params, x, x, cfg)
-    if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
     scale = cfg.head_dim ** -0.5
     local_chunk = cfg.chunk_size if cfg.attention == "chunked_local" else 0
-    if use_kernels:
-        from repro.kernels import ops as kops
-        out = kops.flash_attention(q, k, v, causal=True, scale=scale, chunk=local_chunk)
-    elif t > 1024:
-        from repro.models.flash_ref import flash_attention_ref
-        out = flash_attention_ref(q, k, v, causal=True, scale=scale,
-                                  chunk=local_chunk)
-    else:
-        nrep = cfg.n_heads // cfg.n_kv_heads
-        if local_chunk:
-            mask = chunk_mask(t, t, local_chunk)[None, None]
+    with jax.named_scope("attn"):
+        q, k, v = _proj_qkv(params, x, x, cfg)
+        if use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        if use_kernels:
+            from repro.kernels import ops as kops
+            out = kops.flash_attention(q, k, v, causal=True, scale=scale,
+                                       chunk=local_chunk)
+        elif t > 1024:
+            from repro.models.flash_ref import flash_attention_ref
+            out = flash_attention_ref(q, k, v, causal=True, scale=scale,
+                                      chunk=local_chunk)
         else:
-            mask = causal_mask(t, t)[None, None]
-        out = sdpa(q, _repeat_kv(k, nrep), _repeat_kv(v, nrep), mask, scale)
-    out = out.reshape(b, t, cfg.q_dim) @ params["wo"]
-    if t >= span:                                     # chunked_local: keep tail
-        ck, cv = k[:, t - span:], v[:, t - span:]
-    else:
-        pad = jnp.zeros((b, span - t) + k.shape[2:], k.dtype)
-        ck, cv = jnp.concatenate([k, pad], 1), jnp.concatenate([v, pad], 1)
+            nrep = cfg.n_heads // cfg.n_kv_heads
+            if local_chunk:
+                mask = chunk_mask(t, t, local_chunk)[None, None]
+            else:
+                mask = causal_mask(t, t)[None, None]
+            out = sdpa(q, _repeat_kv(k, nrep), _repeat_kv(v, nrep), mask,
+                       scale)
+        out = out.reshape(b, t, cfg.q_dim) @ params["wo"]
+    with jax.named_scope("kv_write"):
+        if t >= span:                                 # chunked_local: keep tail
+            ck, cv = k[:, t - span:], v[:, t - span:]
+        else:
+            pad = jnp.zeros((b, span - t) + k.shape[2:], k.dtype)
+            ck, cv = jnp.concatenate([k, pad], 1), jnp.concatenate([v, pad], 1)
     return out, ck, cv

@@ -154,15 +154,17 @@ def block_prefill(cfg: ModelConfig, bp: dict, x: jax.Array, idx,
                                      use_kernels=uk, state=st0)
         x = x + h
         return x, {"conv": st["conv"].astype(dtype), "ssm": st["ssm"]}
+    # profile scopes: attn and kv_write inside prefill_attn, mlp here
     h, ck, cv = attn.prefill_attn(bp["attn"], cfg, rmsnorm(bp["ln1"], x),
                                   positions, span, use_kernels=uk)
     x = x + h
-    h = rmsnorm(bp["ln2"], x)
-    if cfg.n_experts:
-        y, _ = moe_mod.apply_moe(bp["moe"], cfg, h)
-        x = x + y
-    else:
-        x = x + apply_mlp(bp["mlp"], h, cfg.act)
+    with jax.named_scope("mlp"):
+        h = rmsnorm(bp["ln2"], x)
+        if cfg.n_experts:
+            y, _ = moe_mod.apply_moe(bp["moe"], cfg, h)
+            x = x + y
+        else:
+            x = x + apply_mlp(bp["mlp"], h, cfg.act)
     return x, {"k": ck.astype(dtype), "v": cv.astype(dtype)}
 
 
@@ -204,17 +206,20 @@ def block_decode_paged(cfg: ModelConfig, bp: dict, x: jax.Array,
                        kp: jax.Array, vp: jax.Array, block_tables: jax.Array,
                        pos: jax.Array, idx, uk: bool):
     """One-token decode against a paged KV pool (attention-cache families
-    only — the assembly gates ssm/rwkv/hybrid to the dense path)."""
+    only — the assembly gates ssm/rwkv/hybrid to the dense path).  Profile
+    scopes: attn and kv_write inside decode_attn_paged, mlp here."""
     h, kp, vp = attn.decode_attn_paged(bp["attn"], cfg, rmsnorm(bp["ln1"], x),
                                        kp, vp, block_tables, pos,
                                        use_kernels=uk)
     x = x + h
-    h = rmsnorm(bp["ln2"], x)
-    if cfg.n_experts:
-        y, _ = moe_mod.apply_moe(bp["moe"], cfg, h, group_size=max(1, x.shape[0]))
-        x = x + y
-    else:
-        x = x + apply_mlp(bp["mlp"], h, cfg.act)
+    with jax.named_scope("mlp"):
+        h = rmsnorm(bp["ln2"], x)
+        if cfg.n_experts:
+            y, _ = moe_mod.apply_moe(bp["moe"], cfg, h,
+                                     group_size=max(1, x.shape[0]))
+            x = x + y
+        else:
+            x = x + apply_mlp(bp["mlp"], h, cfg.act)
     return x, kp, vp
 
 

@@ -151,9 +151,10 @@ def _prefill_trunk(cfg: ModelConfig, params: dict, batch: Dict[str, jax.Array],
     uk = rcfg.use_kernels
     tokens = batch["tokens"]
     bsz = tokens.shape[0]
-    x = embed_tokens(params["embed"], tokens, cdt)
-    if "frontend" in batch:
-        x = jnp.concatenate([batch["frontend"].astype(cdt), x], axis=1)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params["embed"], tokens, cdt)
+        if "frontend" in batch:
+            x = jnp.concatenate([batch["frontend"].astype(cdt), x], axis=1)
     t = x.shape[1]
     span = cache_span(cfg, max_len)
     positions = jnp.broadcast_to(jnp.arange(t), (bsz, t))
@@ -185,7 +186,8 @@ def _prefill_trunk(cfg: ModelConfig, params: dict, batch: Dict[str, jax.Array],
         x, ak, av = carry
     else:
         x = carry
-    x = rmsnorm(params["final_ln"], x)
+    with jax.named_scope("head"):
+        x = rmsnorm(params["final_ln"], x)
     return x, layer_caches, ((ak, av) if n_attn else None)
 
 
@@ -195,7 +197,8 @@ def lm_prefill(cfg: ModelConfig, params: dict, batch: Dict[str, jax.Array],
     cdt = _dt(rcfg.compute_dtype)
     x, layer_caches, attn = _prefill_trunk(cfg, params, batch, rcfg, max_len)
     bsz, t = x.shape[:2]
-    logits = x[:, -1] @ head_weight(cfg, params, cdt)
+    with jax.named_scope("head"):
+        logits = x[:, -1] @ head_weight(cfg, params, cdt)
     cache = {"layers": layer_caches, "pos": jnp.full((bsz,), t, jnp.int32)}
     if attn is not None:
         cache["ak"], cache["av"] = attn
@@ -219,8 +222,9 @@ def lm_prefill_padded(cfg: ModelConfig, params: dict,
     x, layer_caches, attn = _prefill_trunk(cfg, params, batch, rcfg, max_len)
     bsz = x.shape[0]
     lengths = jnp.asarray(lengths, jnp.int32)
-    h = x[jnp.arange(bsz), lengths - 1]
-    logits = h @ head_weight(cfg, params, cdt)
+    with jax.named_scope("head"):
+        h = x[jnp.arange(bsz), lengths - 1]
+        logits = h @ head_weight(cfg, params, cdt)
     cache = {"layers": layer_caches, "pos": lengths}
     if attn is not None:
         cache["ak"], cache["av"] = attn
@@ -389,7 +393,8 @@ def lm_decode_step_pool(cfg: ModelConfig, params: dict, cache: dict,
     """
     cdt = _dt(rcfg.compute_dtype)
     uk = rcfg.use_kernels
-    x = embed_tokens(params["embed"], tokens, cdt)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params["embed"], tokens, cdt)
     pos = cache["pos"]
 
     def body(carry, inp):
@@ -403,8 +408,9 @@ def lm_decode_step_pool(cfg: ModelConfig, params: dict, cache: dict,
         body, x,
         (params["blocks"], cache["layers"]["k"], cache["layers"]["v"]),
         cfg.n_layers, rcfg.unroll_layers)
-    x = rmsnorm(params["final_ln"], x)
-    logits = x[:, -1] @ head_weight(cfg, params, cdt)
+    with jax.named_scope("head"):
+        x = rmsnorm(params["final_ln"], x)
+        logits = x[:, -1] @ head_weight(cfg, params, cdt)
     return logits, {"layers": new_layers, "pos": pos + 1}
 
 

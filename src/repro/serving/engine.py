@@ -67,6 +67,7 @@ from repro.serving.metrics import EngineSnapshot, MetricsCollector
 from repro.serving.sampling import (GREEDY, Sampler, SamplingParams,
                                     resolve_sampling)
 from repro.serving.scheduler import AdmissionScheduler, SchedulerConfig
+from repro.serving.spans import Spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,13 +143,20 @@ def _shared_prefill_jits(model: Model, max_len: int):
     jax.jit caches are per wrapper object, and a fleet builds one engine
     per worker from the SAME model — per-instance wrappers would re-trace
     and re-compile identical prefill programs once per worker.  Model is
-    frozen/hashable and holds no params, so caching it is cheap."""
-    one = jax.jit(lambda p, b: model.prefill(p, b, max_len))
+    frozen/hashable and holds no params, so caching it is cheap.
+
+    The programs are named (``jit_prefill_single``, ``jit_prefill_batched``)
+    so a profile tells them apart."""
+    def prefill_single(p, b):
+        return model.prefill(p, b, max_len)
+
+    one = jax.jit(prefill_single)
     batched = model.decode_state.batched_prefill
     many = None
     if batched is not None:
-        many = jax.jit(
-            lambda p, toks, lens: batched(p, {"tokens": toks}, lens, max_len))
+        def prefill_batched(p, toks, lens):
+            return batched(p, {"tokens": toks}, lens, max_len)
+        many = jax.jit(prefill_batched)
     return one, many
 
 
@@ -184,9 +192,12 @@ class ServeEngine:
                 f"real prompt K/V")
         self.max_prefill_batch = max(1, min(max_prefill_batch, max_batch))
         self.slots: List[Optional[Request]] = [None] * max_batch
+        # host spans (serve.*) on the profiler's trace and, timed on this
+        # engine's clock, in its collector's phases
+        self._spans = Spans(self._now)
         # the Sampler owns the per-lane filter + PRNG state; lane_sampling
         # aliases its SoA arrays (pre-Sampler code paths mutate in place)
-        self.sampler = Sampler(max_batch)
+        self.sampler = Sampler(max_batch, spans=self._spans)
         self.lane_sampling = self.sampler.lanes
         self._rid = 0
         self.steps = 0
@@ -196,6 +207,7 @@ class ServeEngine:
         self.backend = make_backend(model, max_batch, max_len, self.config)
         self.metrics = MetricsCollector(n_slots=max_batch,
                                         n_blocks=self.backend.n_blocks)
+        self._spans.collector = self.metrics
 
         self._prefill1, self._prefill_n = _shared_prefill_jits(model, max_len)
 
@@ -335,38 +347,42 @@ class ServeEngine:
         ``widths[j]`` is the prefill width request j was padded to (its
         bucket length, or its exact context length on the fallback path)."""
         ls = self.lane_sampling
+        rids = [req.rid for req, _ in items]
         for (req, _), slot in zip(items, slots):
             ls.set_lane(slot, req.sampling)
             if req.saved_key is not None:     # resume: continue the stream
                 ls.key[slot] = req.saved_key
-        toks = self.sampler.sample(logits[:, :self.vocab],
-                                   lanes=np.asarray(slots))
+        with self._spans.span("serve.sample", rids=rids):
+            toks = self.sampler.sample(logits[:, :self.vocab],
+                                       lanes=np.asarray(slots))
         t_first = self._now()
-        for j, ((req, res), slot) in enumerate(zip(items, slots)):
-            n_ctx = self._ctx_len(req)
-            tok = int(toks[j])
-            req.out_tokens.append(tok)
-            if req.admitted_t is None:
-                req.first_token_t = t_first
-                self.metrics.on_admit(req, now)
-            else:
-                self.metrics.on_resume(req, now)
-            req.admitted_t = now
-            req.saved_key = None
-            # paste EVERY admission — even one that finishes right here —
-            # so blocks the reservation registered in the prefix cache
-            # hold real content before anyone prefix-matches them
-            self.backend.prefill_paste(slot, group_cache, j, n_ctx,
-                                       widths[j], res)
-            if len(req.out_tokens) >= req.max_new or tok == self.eos_id:
-                # finished at admission: never occupies a decode lane
-                req.done_t = t_first
-                ls.clear_lane(slot)
-                self.backend.release(slot, tokens=self._cache_tokens(req))
-                self.finished.append(req)
-                self.metrics.on_finish(req, t_first)
-                continue
-            self.slots[slot] = req
+        with self._spans.span("serve.paste", rids=rids):
+            for j, ((req, res), slot) in enumerate(zip(items, slots)):
+                n_ctx = self._ctx_len(req)
+                tok = int(toks[j])
+                req.out_tokens.append(tok)
+                if req.admitted_t is None:
+                    req.first_token_t = t_first
+                    self.metrics.on_admit(req, now)
+                    self.metrics.on_first_token(t_first - now)
+                else:
+                    self.metrics.on_resume(req, now)
+                req.admitted_t = now
+                req.saved_key = None
+                # paste EVERY admission — even one that finishes right here —
+                # so blocks the reservation registered in the prefix cache
+                # hold real content before anyone prefix-matches them
+                self.backend.prefill_paste(slot, group_cache, j, n_ctx,
+                                           widths[j], res)
+                if len(req.out_tokens) >= req.max_new or tok == self.eos_id:
+                    # finished at admission: never occupies a decode lane
+                    req.done_t = t_first
+                    ls.clear_lane(slot)
+                    self.backend.release(slot, tokens=self._cache_tokens(req))
+                    self.finished.append(req)
+                    self.metrics.on_finish(req, t_first)
+                    continue
+                self.slots[slot] = req
 
     def _admit(self) -> None:
         # loop: requests that finish AT admission (max_new=1 / instant EOS)
@@ -386,6 +402,13 @@ class ServeEngine:
             capacity=self.backend.capacity_tokens)
         if not batch:
             return False
+        # the round's span counts from ``now``, the scheduler's pop included
+        with self._spans.span("serve.admit", start=now,
+                              rids=[r.rid for r in batch]):
+            return self._admit_batch(batch, free, now)
+
+    def _admit_batch(self, batch: List[Request], free: List[int],
+                     now: float) -> bool:
         n_done_before = len(self.finished)
 
         # reserve capacity per request (allocate-on-admit): reject what can
@@ -443,18 +466,23 @@ class ServeEngine:
                     seq = self._prefill_tokens(req)
                     toks[j, :len(seq)] = seq
                     lens[j] = len(seq)
-                logits, group_cache = self._prefill_n(
-                    self.params, jnp.asarray(toks), jnp.asarray(lens))
+                with self._spans.span("serve.prefill", bucket=blen,
+                                      rows=len(chunk),
+                                      rids=[r.rid for r, _ in chunk]):
+                    logits, group_cache = self._prefill_n(
+                        self.params, jnp.asarray(toks), jnp.asarray(lens))
                 self.metrics.on_prefill(len(chunk), blen * len(chunk))
                 slots = [free.pop(0) for _ in chunk]
                 self._admit_group(chunk, slots, logits, group_cache, now,
                                   widths=[blen] * len(chunk))
         for req, res in fallback:
             seq = self._prefill_tokens(req)
-            b = {"tokens": jnp.asarray(seq[None])}
-            for k, v in req.extra.items():
-                b[k] = jnp.asarray(v[None])
-            logits, one_cache = self._prefill1(self.params, b)
+            with self._spans.span("serve.prefill", bucket=len(seq), rows=1,
+                                  rids=[req.rid]):
+                b = {"tokens": jnp.asarray(seq[None])}
+                for k, v in req.extra.items():
+                    b[k] = jnp.asarray(v[None])
+                logits, one_cache = self._prefill1(self.params, b)
             self.metrics.on_prefill(1, self._ctx_len(req))
             self._admit_group([(req, res)], [free.pop(0)], logits, one_cache,
                               now, widths=[self._ctx_len(req)])
@@ -540,14 +568,15 @@ class ServeEngine:
         private block at its next position (grow / COW-split / uncache —
         see ``CacheBackend.prepare_lane``); exhaustion preempts victims
         (possibly the needy lane itself) until it frees."""
-        for slot in range(self.max_batch):
-            if self.slots[slot] is None:
-                continue
-            while not self.backend.prepare_lane(slot):
-                victim = self._pick_victim()
-                self.preempt(victim)
-                if victim == slot:
-                    break
+        with self._spans.span("serve.prepare"):
+            for slot in range(self.max_batch):
+                if self.slots[slot] is None:
+                    continue
+                while not self.backend.prepare_lane(slot):
+                    victim = self._pick_victim()
+                    self.preempt(victim)
+                    if victim == slot:
+                        break
 
     # ------------------------------------------------------------------
     # decode
@@ -557,6 +586,10 @@ class ServeEngine:
 
     def step(self) -> int:
         """Admit + one decode step for all active lanes. Returns #active."""
+        with self._spans.span("serve.step", step_num=self.steps):
+            return self._step()
+
+    def _step(self) -> int:
         # grow RUNNING lanes before admission takes the last free blocks —
         # else a fresh admission pays a whole prefill only to be the LIFO
         # victim of an older lane's growth this same step
@@ -568,22 +601,31 @@ class ServeEngine:
         self._prepare_lanes()
         if self.active() == 0:
             return 0
-        toks = np.zeros((self.max_batch, 1), np.int32)
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            # normally the lane's last sampled token; a lane admitted
-            # without prefill (restore / full hit) re-feeds its last
-            # context token to produce the next logits
-            toks[i, 0] = req.out_tokens[-1] if req.out_tokens \
-                else req.prompt[-1]
-        active = np.asarray([s is not None for s in self.slots])
-        logits = self.backend.step(self.params, toks, active)
-        ls = self.lane_sampling
+        with self._spans.span("serve.decode"):
+            toks = np.zeros((self.max_batch, 1), np.int32)
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                # normally the lane's last sampled token; a lane admitted
+                # without prefill (restore / full hit) re-feeds its last
+                # context token to produce the next logits
+                toks[i, 0] = req.out_tokens[-1] if req.out_tokens \
+                    else req.prompt[-1]
+            active = np.asarray([s is not None for s in self.slots])
+            logits = self.backend.step(self.params, toks, active)
         # one host transfer per step: Sampler.sample returns host numpy;
         # tolist() converts the whole batch at once so the per-lane loop
         # below never touches an array element-wise (repro-lint R004)
-        nxt = self.sampler.sample(logits[:, :self.vocab]).tolist()
+        with self._spans.span("serve.sample"):
+            nxt = self.sampler.sample(logits[:, :self.vocab]).tolist()
+        with self._spans.span("serve.finish"):
+            self._finish_step(nxt)
+        return self.active()
+
+    def _finish_step(self, nxt: List[int]) -> None:
+        """Per-lane bookkeeping after a decode step's tokens are on the
+        host: append, stamp, free finished lanes, count the step."""
+        ls = self.lane_sampling
         now = self._now()
         busy = self.active()          # before the finish-scan frees lanes
         for i, req in enumerate(self.slots):
@@ -593,6 +635,7 @@ class ServeEngine:
             req.out_tokens.append(tok)
             if req.first_token_t is None:   # prefill-skipping admissions
                 req.first_token_t = now
+                self.metrics.on_first_token(now - req.admitted_t)
             if len(req.out_tokens) >= req.max_new or tok == self.eos_id:
                 req.done_t = now
                 self.slots[i] = None                # lane freed immediately
@@ -603,7 +646,6 @@ class ServeEngine:
         self.steps += 1
         self.metrics.on_step(self.scheduler.depth, busy, now,
                              blocks_in_use=self.backend.blocks_in_use)
-        return self.active()
 
     def run_until_drained(self, max_steps: int = 10_000) -> List[Request]:
         for _ in range(max_steps):
@@ -639,6 +681,7 @@ class ServeEngine:
         self.steps = 0
         self.metrics = MetricsCollector(n_slots=self.max_batch,
                                         n_blocks=self.backend.n_blocks)
+        self._spans.collector = self.metrics
         self.backend.reset_counters()
 
     def metrics_snapshot(self) -> EngineSnapshot:

@@ -9,6 +9,11 @@ Definitions (standard serving vocabulary):
 * **tokens/s** — generated tokens over the engine's active wall-clock.
 * **queue depth / slot utilisation** — step-weighted means sampled once per
   engine step, i.e. what the engine actually saw while running.
+* **prefill wait** — start of the admission round that first admitted a
+  request to its first token sampled: ``first_token_t - admitted_t``.
+* **phases** — per host span of the serving path (``serve.*``, see
+  :mod:`repro.serving.spans`): count, total and longest seconds, on the
+  engine's clock; the longest step keeps its split by direct child span.
 
 ``MetricsCollector`` is pure bookkeeping (no jax); the engine feeds it
 events and asks for a :class:`EngineSnapshot` — a frozen, structured view
@@ -28,6 +33,8 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
+
+STEP_SPAN = "serve.step"    # the engine step's span (repro.serving.spans)
 
 
 def _percentile(xs: List[float], q: float) -> float:
@@ -55,6 +62,14 @@ class LatencyStats:
         return cls(count=len(xs), mean=_mean(xs),
                    p50=_percentile(xs, 0.50), p95=_percentile(xs, 0.95),
                    max=max(xs) if xs else float("nan"))
+
+
+@dataclasses.dataclass(frozen=True)
+class PhaseStats:
+    """One host span's totals on the engine's clock."""
+    count: int
+    total_s: float
+    max_s: float
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,6 +116,12 @@ class EngineSnapshot:
     spec_accepted_tokens: int = 0      # proposals the target agreed with
     spec_acceptance_rate: float = 0.0  # accepted / drafted (token-weighted)
     spec_accepted_series: Tuple[int, ...] = ()  # accepted count per round
+    # host phases (repro.serving.spans), on the engine's clock
+    phases: Dict[str, PhaseStats] = dataclasses.field(default_factory=dict)
+    step_max_s: float = 0.0            # longest serve.step
+    step_max_phases: Dict[str, float] = dataclasses.field(
+        default_factory=dict)          # its seconds by direct child span
+    prefill_wait: LatencyStats = LatencyStats.of([])  # round start -> 1st tok
 
     def as_dict(self) -> Dict:
         return dataclasses.asdict(self)
@@ -274,8 +295,38 @@ class MetricsCollector:
         self.spec_accepted_series: List[int] = []
         self._t_first: Optional[float] = None
         self._t_last: Optional[float] = None
+        self.prefill_wait: List[float] = []
+        self.phases: Dict[str, List[float]] = {}    # name -> [n, total, max]
+        self.step_max_s = 0.0
+        self.step_max_phases: Dict[str, float] = {}
+        self._step_split: Dict[str, float] = {}
 
     # ------------------------------------------------------------------
+    def on_phase(self, name: str, seconds: float,
+                 parent: Optional[str]) -> None:
+        """A host span closed (``repro.serving.spans``); ``parent`` is the
+        name of the span it was opened in."""
+        ph = self.phases.get(name)
+        if ph is None:
+            self.phases[name] = [1, seconds, seconds]
+        else:
+            ph[0] += 1
+            ph[1] += seconds
+            ph[2] = max(ph[2], seconds)
+        split = self._step_split
+        if name == STEP_SPAN:
+            if seconds > self.step_max_s or self.phases[name][0] == 1:
+                self.step_max_s = seconds
+                self.step_max_phases = dict(split)
+            split.clear()
+        elif parent == STEP_SPAN:
+            split[name] = split.get(name, 0.0) + seconds
+
+    def on_first_token(self, wait_s: float) -> None:
+        """A request's first token, ``wait_s`` after the start of the
+        admission round that first admitted it."""
+        self.prefill_wait.append(wait_s)
+
     def on_prefill(self, n_requests: int, n_tokens: int = 0) -> None:
         self.prefill_dispatches += 1
         self.prefill_requests += n_requests
@@ -383,4 +434,9 @@ class MetricsCollector:
                 self.spec_accepted_tokens / self.spec_drafted_tokens
                 if self.spec_drafted_tokens else 0.0),
             spec_accepted_series=tuple(self.spec_accepted_series),
+            phases={k: PhaseStats(int(n), t, m)
+                    for k, (n, t, m) in sorted(self.phases.items())},
+            step_max_s=self.step_max_s,
+            step_max_phases=dict(sorted(self.step_max_phases.items())),
+            prefill_wait=LatencyStats.of(self.prefill_wait),
         )

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serving.spans import Spans
+
 NEG_INF = -1e30
 
 
@@ -189,11 +191,19 @@ class Sampler:
     The state is the same :class:`LaneSampling` SoA the engine always
     kept (exposed as ``.lanes`` — engine/fleet code that snapshots a
     lane's key for preemption keeps working on the arrays in place).
+
+    :meth:`sample` opens the spans ``serve.sample.upload`` (lane state
+    and logits to the device), ``.dispatch`` (the ``sample_tokens``
+    call), ``.wait`` (until its outputs are ready) and ``.download``
+    (tokens and advanced keys to the host).
     """
 
-    def __init__(self, n_lanes: int):
+    def __init__(self, n_lanes: int, spans: Optional[Spans] = None):
         self.n_lanes = n_lanes
         self.lanes = LaneSampling.empty(n_lanes)
+        # the engine passes its own, so the sampler's phases land in the
+        # engine's collector; alone, a sampler only annotates a trace
+        self.spans = spans or Spans()
 
     # -- lane state ----------------------------------------------------
     def set_lane(self, lane: int, params: SamplingParams) -> None:
@@ -220,17 +230,24 @@ class Sampler:
         (default: row i is lane i); ``mask`` freezes masked-out lanes'
         streams (their tokens are garbage)."""
         ls = self.lanes
+        sp = self.spans
         idx = (np.arange(logits.shape[0]) if lanes is None
                else np.asarray(lanes))
-        args = (jnp.asarray(ls.temperature[idx]), jnp.asarray(ls.top_k[idx]),
-                jnp.asarray(ls.top_p[idx]), jnp.asarray(ls.key[idx]))
-        if mask is None:
-            toks, new_kd = sample_tokens(jnp.asarray(logits), *args)
-        else:
-            toks, new_kd = sample_tokens_masked(
-                jnp.asarray(logits), *args, jnp.asarray(mask))
-        ls.key[idx] = np.asarray(new_kd)
-        return np.asarray(toks)
+        with sp.span("serve.sample.upload"):
+            logits = jnp.asarray(logits)
+            args = (jnp.asarray(ls.temperature[idx]),
+                    jnp.asarray(ls.top_k[idx]), jnp.asarray(ls.top_p[idx]),
+                    jnp.asarray(ls.key[idx]))
+            if mask is not None:
+                args += (jnp.asarray(mask),)
+        with sp.span("serve.sample.dispatch"):
+            fn = sample_tokens if mask is None else sample_tokens_masked
+            toks, new_kd = fn(logits, *args)
+        with sp.span("serve.sample.wait"):
+            jax.block_until_ready((toks, new_kd))
+        with sp.span("serve.sample.download"):
+            ls.key[idx] = np.asarray(new_kd)
+            return np.asarray(toks)
 
     def accept(self, window_logits, drafted: np.ndarray,
                active: np.ndarray, limit: Sequence[int],

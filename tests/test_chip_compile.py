@@ -11,6 +11,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,8 @@ from repro.kernels.decode_attention import decode_attention_fwd
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.paged_attention import paged_decode_attention_fwd
 from repro.models.api import build_model
+from repro.serving.backends import _pool_step_jit
+from repro.serving.engine import _shared_prefill_jits
 
 H, G, D = 32, 8, 128
 BF16 = jnp.bfloat16
@@ -128,3 +131,47 @@ def test_engine_batched_prefill_compiles(one_chip, granite_2l):
                  _shape(one_chip, (4, 128), jnp.int32),
                  _shape(one_chip, (4,), jnp.int32))
     _assert_kernel(c)
+
+
+SCOPES = ("embed", "attn", "kv_write", "mlp", "head")
+
+
+def _assert_names(compiled, program, kernel):
+    """The program's own name, every model scope in its operations'
+    metadata, and each Pallas call named for its kernel (the instruction
+    and its op_name)."""
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_{program},")
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in SCOPES:
+        assert any(n.startswith(f"jit({program})/") and f"/{scope}/" in n
+                   for n in names), scope
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for ln in calls:
+        assert re.match(rf"\s*(ROOT )?%{kernel}(\.\d+)? = ", ln), ln
+        assert f"/attn/{kernel}/pallas_call" in ln
+
+
+def test_engine_decode_step_carries_its_names(one_chip, granite_2l):
+    """The engine's own jitted decode step: ``jit_decode_pool_step``, the
+    layer scopes, and the paged attention kernel by name."""
+    model, params = granite_2l
+    ds = model.decode_state
+    cache = jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
+                         jax.eval_shape(lambda: ds.pool_init(8, 1024, 16)))
+    c = _pool_step_jit(ds).lower(
+        params, cache, _shape(one_chip, (8, 1), jnp.int32),
+        _shape(one_chip, (8, 2048 // 16), jnp.int32)).compile()
+    _assert_names(c, "decode_pool_step", "paged_attention")
+
+
+def test_engine_batched_prefill_carries_its_names(one_chip, granite_2l):
+    """The engine's own batched prefill: ``jit_prefill_batched``, the
+    layer scopes, and the flash attention kernel by name."""
+    model, params = granite_2l
+    _, prefill = _shared_prefill_jits(model, 2048)
+    c = prefill.lower(params, _shape(one_chip, (4, 128), jnp.int32),
+                      _shape(one_chip, (4,), jnp.int32)).compile()
+    _assert_names(c, "prefill_batched", "flash_attention")
