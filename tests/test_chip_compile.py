@@ -155,16 +155,24 @@ def _assert_names(compiled, program, kernel):
 
 
 def test_engine_decode_step_carries_its_names(one_chip, granite_2l):
-    """The engine's own jitted decode step: ``jit_decode_pool_step``, the
-    layer scopes, and the paged attention kernel by name."""
+    """The engine's own jitted decode step at the batch cell's engine shapes
+    (32 lanes, 2000 blocks of 16 and the sink, 2048-token lanes):
+    ``jit_decode_pool_step``, the layer scopes, and one Pallas call, the
+    paged attention kernel by name, which takes the engine's s32[32,128]
+    block table: the benchmark tells the decode program from the prefill
+    by that operand."""
     model, params = granite_2l
     ds = model.decode_state
     cache = jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
-                         jax.eval_shape(lambda: ds.pool_init(8, 1024, 16)))
+                         jax.eval_shape(lambda: ds.pool_init(32, 2000, 16)))
     c = _pool_step_jit(ds).lower(
-        params, cache, _shape(one_chip, (8, 1), jnp.int32),
-        _shape(one_chip, (8, 2048 // 16), jnp.int32)).compile()
+        params, cache, _shape(one_chip, (32, 1), jnp.int32),
+        _shape(one_chip, (32, 2048 // 16), jnp.int32)).compile()
     _assert_names(c, "decode_pool_step", "paged_attention")
+    calls = [ln for ln in c.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    assert "s32[32,128]" in calls[0]
 
 
 def test_engine_batched_prefill_carries_its_names(one_chip, granite_2l):

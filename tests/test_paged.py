@@ -112,31 +112,136 @@ def test_dense_vs_paged_decode_logit_parity(small_lm):
         tok = int(jnp.argmax(ld[0, :v]))
 
 
-def test_paged_kernel_matches_gather_reference():
+# (h, g, d, bs, nb, table width, pool dtype, STEP_BYTES or None for the
+#  module's own, each lane's blocks or count of fresh blocks (0: an
+#  all-sink row), each lane's pos)
+KERNEL_CASES = {
+    # the original case: one gather covers the whole 4-block table
+    "one_gather": (4, 2, 16, 8, 9, 4, "float32", None,
+                   [[3, 5], [1, 2, 7, 4], [8]], [9, 30, 0]),
+    # 1 KB f32 pages, 3 pages a gather over a 7-wide table: contexts end
+    # mid-gather, on a gather's edge, on the edge plus one page, fill the
+    # whole table; idle sink rows first and in the middle
+    "steps_of_3": (4, 2, 16, 8, 40, 7, "float32", 3 * 1024,
+                   [0, 2, 3, 4, 0, 7, 1], [55, 12, 23, 24, 3, 55, 0]),
+    # granite's page in bf16: 16 pages of (16 x 8, 128) a gather over a
+    # 20-wide table
+    "bf16_granite_page": (32, 8, 128, 16, 64, 20, "bfloat16", None,
+                          [0, 6, 16, 20, 17], [319, 83, 255, 319, 271]),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_paged_kernel_matches_gather_reference(case, monkeypatch):
     """The Pallas paged flash-decode kernel must match the pure-jnp gather
-    path (interpret mode on CPU)."""
+    path (interpret mode on CPU), streaming only each lane's live blocks
+    whatever the gather size; a lane whose row starts with the sink block
+    holds none and returns zeros."""
     from repro.kernels import ops as kops
+    from repro.kernels import paged_attention as pa
     from repro.models.attention import _repeat_kv, sdpa
 
+    h, g, d, bs, nb, mb, dtype, step_bytes, lanes, pos = KERNEL_CASES[case]
+    if step_bytes is not None:
+        monkeypatch.setattr(pa, "STEP_BYTES", step_bytes)
     rng = np.random.default_rng(0)
-    b, h, g, d, nb, bs, mb = 3, 4, 2, 16, 9, 8, 4
-    q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, g, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, g, d)), jnp.float32)
+    b = len(lanes)
+    dt = jnp.dtype(dtype)
+    q = jnp.asarray(rng.normal(size=(b, 1, h, d)), dt)
+    kp = jnp.asarray(rng.normal(size=(nb, bs, g, d)), dt)
+    vp = jnp.asarray(rng.normal(size=(nb, bs, g, d)), dt)
+    free = list(rng.permutation(np.arange(1, nb)))
     bt = np.zeros((b, mb), np.int32)
-    bt[0, :2] = [3, 5]
-    bt[1, :4] = [1, 2, 7, 4]
-    bt[2, :1] = [8]
-    pos = jnp.asarray([9, 30, 0], jnp.int32)    # last written position
+    for i, x in enumerate(lanes):
+        blocks = x if isinstance(x, list) else [free.pop() for _ in range(x)]
+        bt[i, :len(blocks)] = blocks
+    pos = jnp.asarray(pos, jnp.int32)    # last written position
     bt = jnp.asarray(bt)
     out = kops.paged_decode_attention(q, kp, vp, bt, pos, scale=d ** -0.5)
     span = mb * bs
-    ck = kp[bt].reshape(b, span, g, d)
-    cv = vp[bt].reshape(b, span, g, d)
+    f32 = lambda a: a.astype(jnp.float32)
+    ck = f32(kp)[bt].reshape(b, span, g, d)
+    cv = f32(vp)[bt].reshape(b, span, g, d)
     valid = jnp.arange(span)[None, :] <= pos[:, None]
-    ref = sdpa(q, _repeat_kv(ck, h // g), _repeat_kv(cv, h // g),
+    ref = sdpa(f32(q), _repeat_kv(ck, h // g), _repeat_kv(cv, h // g),
                valid[:, None, None, :], d ** -0.5)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    live = np.asarray(bt[:, 0]) != 0
+    # f32 keeps the original bound; a bf16 pool and output round to 8 bits
+    atol, rtol = (2e-5, 0) if dtype == "float32" else (2e-2, 2e-2)
+    np.testing.assert_allclose(np.asarray(f32(out))[live],
+                               np.asarray(ref)[live], atol=atol, rtol=rtol)
+    assert not np.asarray(f32(out))[~live].any()
+
+
+@pytest.mark.parametrize("path", ["prefix_cache_preempt", "spec_rollback"])
+def test_kernel_engine_matches_gather_engine(small_lm, monkeypatch, path):
+    """The paged engine on the Pallas kernel (interpret mode on CPU) decodes
+    the same tokens as on the gather path, over the block manager's own
+    tables: copy-on-write prefix sharing, preemption (a released row goes
+    back to the sink), the speculative verify window and its rollback.
+    The kernel treats a row that starts with the sink as idle, so every
+    lane a step serves must hold a real first block."""
+    from repro.kernels import ops as kops
+    from repro.serving.backends import PagedBackend
+    from repro.serving.speculative import SpecEngine
+
+    model, params = small_lm
+    # a 16x attention output projection lets what each lane attends to
+    # decide its tokens; at 1x the random model repeats its last token
+    params = {**params, "blocks": {**params["blocks"], "attn": {
+        **params["blocks"]["attn"],
+        "wo": params["blocks"]["attn"]["wo"] * 16}}}
+    kmodel = build_model(model.cfg,
+                         dataclasses.replace(RCFG, use_kernels=True))
+    dcfg = dataclasses.replace(model.cfg, n_layers=1)
+    draft = build_model(dcfg, RCFG)
+    dparams = draft.init(jax.random.key(3))
+    traced, checked = [], []
+    kernel = kops.paged_decode_attention
+    monkeypatch.setattr(kops, "paged_decode_attention",
+                        lambda *a, **k: traced.append(1) or kernel(*a, **k))
+
+    def sink_first_only_when_idle(run):
+        def wrapped(self, params, tokens, active):
+            # every lane the step serves holds a real first block
+            assert (self.block_tables[active, 0] != 0).all()
+            checked.append(1)
+            return run(self, params, tokens, active)
+        return wrapped
+
+    for name in ("step", "verify_step"):
+        monkeypatch.setattr(PagedBackend, name, sink_first_only_when_idle(
+            getattr(PagedBackend, name)))
+    rng = np.random.default_rng(13)
+    v = model.cfg.vocab_size
+    prefix = rng.integers(0, v, size=12)
+    prompts = [np.concatenate([prefix, rng.integers(0, v, size=int(n))])
+               for n in (6, 9, 4, 8)]
+
+    def serve(m):
+        config = EngineConfig(kv_blocks=12, kv_block_size=4,
+                              prefix_cache=path == "prefix_cache_preempt")
+        if path == "prefix_cache_preempt":
+            eng = ServeEngine(m, params, max_batch=4, max_len=64,
+                              config=config)
+        else:
+            eng = SpecEngine(m, params, draft, dparams, max_batch=4,
+                             max_len=64, spec_k=3, config=config)
+        rids = [eng.submit(p, max_new=7) for p in prompts]
+        eng.run_until_drained()
+        done = {r.rid: list(r.out_tokens) for r in eng.finished}
+        return [done[r] for r in rids], eng.metrics_snapshot()
+
+    ref, _ = serve(model)
+    assert not traced
+    got, snap = serve(kmodel)
+    assert traced and checked
+    assert got == ref
+    assert snap.preemptions > 0 and snap.resumes > 0
+    if path == "prefix_cache_preempt":
+        assert snap.prefix_hit_rate > 0
+    else:
+        assert snap.spec_acceptance_rate < 1      # some windows rolled back
 
 
 # ---------------------------------------------------------------------------
