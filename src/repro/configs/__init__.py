@@ -19,6 +19,7 @@ from repro.configs.base import (  # noqa: F401
 
 from repro.configs import (  # noqa: E402
     command_r_35b,
+    granite_4_h_small,
     granite_8b,
     grok1_314b,
     internvl2_1b,
@@ -33,6 +34,7 @@ from repro.configs import (  # noqa: E402
 _MODULES = (
     whisper_small, zamba2_7b, mistral_nemo_12b, yi_34b, granite_8b,
     command_r_35b, llama4_scout_17b_a16e, grok1_314b, rwkv6_1p6b, internvl2_1b,
+    granite_4_h_small,
 )
 
 REGISTRY: Dict[str, ModelConfig] = {m.CONFIG.arch_id: m.CONFIG for m in _MODULES}
@@ -73,10 +75,17 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
         vocab_size=512,
     )
     if cfg.n_experts:
-        kw.update(n_experts=min(cfg.n_experts, 4), top_k=min(cfg.top_k, 2))
+        kw.update(n_experts=min(cfg.n_experts, 4), top_k=min(cfg.top_k, 2),
+                  held_experts=(), shared_expert_ff=(
+                      256 if cfg.shared_expert_ff else 0))
     if cfg.family in ("ssm", "hybrid") and not cfg.rwkv:
-        kw.update(ssm_state=16, ssm_headdim=32,
+        kw.update(ssm_state=16, ssm_headdim=32, ssm_chunk=16,
                   attn_every=2 if cfg.attn_every else 0)
+    if cfg.layer_types:
+        # one short period, both kinds of mixer: m A m A
+        kw.update(layer_types=("mamba", "attention") * 2,
+                  attention_multiplier=(None if cfg.attention_multiplier is None
+                                        else 32 ** -1.0))
     if cfg.n_enc_layers:
         kw.update(n_enc_layers=2)
     if cfg.frontend:

@@ -29,9 +29,16 @@ class ModelConfig:
     head_dim: Optional[int] = None          # default d_model // n_heads
 
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0              # routed experts (the router's width)
     top_k: int = 0
     n_shared_experts: int = 0
+    shared_expert_ff: int = 0       # shared expert width; 0 = n_shared * d_ff
+    # experts [lo, hi) this device holds (expert parallelism cut to one
+    # chip's share); () = all of them
+    held_experts: Tuple[int, int] = ()
+    # "softmax_all": softmax over every expert, then the top_k probabilities
+    # (unnormalised); "topk_softmax": softmax over the top_k logits only
+    router: str = "softmax_all"
 
     # SSM (Mamba2) / hybrid
     ssm_state: int = 0
@@ -39,6 +46,10 @@ class ModelConfig:
     ssm_headdim: int = 64
     ssm_conv: int = 4
     attn_every: int = 0            # hybrid: apply shared attn block every k layers
+    ssm_chunk: int = 64            # chunk of the SSD prefill kernel
+    # hybrid with per-layer mixers: "mamba" | "attention" per layer, each
+    # followed by its own expert FFN (granitemoehybrid); () elsewhere
+    layer_types: Tuple[str, ...] = ()
 
     # RWKV6
     rwkv: bool = False
@@ -48,6 +59,8 @@ class ModelConfig:
     chunk_size: int = 8192         # for chunked_local
     rope_theta: float = 1e6
     qkv_bias: bool = False
+    position_embedding: str = "rope"   # rope | nope
+    attention_multiplier: Optional[float] = None   # score scale; None = 1/sqrt(dh)
 
     # Encoder-decoder (whisper) / multimodal frontends
     n_enc_layers: int = 0
@@ -59,6 +72,10 @@ class ModelConfig:
     norm_eps: float = 1e-5
     act: str = "silu"               # silu | gelu
     glu: bool = True                # gated MLP (3 matrices) vs plain (2)
+    # granite multipliers: embeddings x, each residual branch x, logits /
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     source: str = ""                # provenance note [source; tier]
 
@@ -74,6 +91,42 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def attn_scale(self) -> float:
+        if self.attention_multiplier is not None:
+            return self.attention_multiplier
+        return self.head_dim ** -0.5
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return tuple(self.held_experts) if self.held_experts \
+            else (0, self.n_experts)
+
+    @property
+    def n_held_experts(self) -> int:
+        lo, hi = self.held_range
+        return hi - lo
+
+    @property
+    def shared_ff(self) -> int:
+        return self.shared_expert_ff or self.n_shared_experts * self.d_ff
+
+    def mamba_params(self) -> int:
+        """One Mamba2 mixer (n_groups = 1, conv bias, no projection bias)."""
+        d_in = self.ssm_expand * self.d_model
+        nheads = d_in // self.ssm_headdim
+        conv_dim = d_in + 2 * self.ssm_state
+        in_proj = self.d_model * (2 * d_in + 2 * self.ssm_state + nheads)
+        return (in_proj + d_in * self.d_model + conv_dim * (self.ssm_conv + 1)
+                + 3 * nheads + d_in)
+
+    def moe_params(self) -> int:
+        """Held routed experts, the router and the shared expert."""
+        mats = 3 if self.glu else 2
+        return (self.n_held_experts * mats * self.d_model * self.d_ff
+                + self.d_model * self.n_experts
+                + mats * self.d_model * self.shared_ff)
 
     def mlp_params(self) -> int:
         mats = 3 if self.glu else 2
@@ -112,6 +165,11 @@ class ModelConfig:
         return mult * self.vocab_size * self.d_model
 
     def total_params(self) -> int:
+        if self.layer_types:
+            n_attn = sum(t == "attention" for t in self.layer_types[:self.n_layers])
+            return ((self.n_layers - n_attn) * self.mamba_params()
+                    + n_attn * self.attn_params()
+                    + self.n_layers * self.moe_params() + self.embed_params())
         n = self.n_layers * self.layer_params() + self.embed_params()
         if self.family == "hybrid" and self.attn_every:
             # one shared attention+MLP block (zamba2-style), applied periodically

@@ -115,16 +115,17 @@ def rwkv6_scan(r, k, v, w, u):
 # mamba2 ssd
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=())
-def _ssd(x, dt, A, B, C, D):
-    return mamba2_ssd_fwd(x, dt, A, B, C, D, interpret=_interpret())
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _ssd(x, dt, A, B, C, D, chunk):
+    return mamba2_ssd_fwd(x, dt, A, B, C, D, chunk=chunk,
+                          interpret=_interpret())
 
 
-def _ssd_f(x, dt, A, B, C, D):
-    return _ssd(x, dt, A, B, C, D), (x, dt, A, B, C, D)
+def _ssd_f(x, dt, A, B, C, D, chunk):
+    return _ssd(x, dt, A, B, C, D, chunk), (x, dt, A, B, C, D)
 
 
-def _ssd_b(res, g):
+def _ssd_b(chunk, res, g):
     # gradient flows through y only; the final state is consumed at decode
     # time (no training path) — its cotangent is dropped
     gy, _gs = g
@@ -136,9 +137,9 @@ def _ssd_b(res, g):
 _ssd.defvjp(_ssd_f, _ssd_b)
 
 
-def mamba2_ssd(x, dt, A, B, C, D):
+def mamba2_ssd(x, dt, A, B, C, D, chunk: int = 64):
     """Returns (y, final_state)."""
-    return _ssd(x, dt, A, B, C, D)
+    return _ssd(x, dt, A, B, C, D, chunk)
 
 
 # ---------------------------------------------------------------------------

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, RunConfig, ShapeConfig
 from repro.models import encdec as ED
+from repro.models import hybrid as HY
 from repro.models import lm as LM
 
 
@@ -159,6 +160,8 @@ def build_model(cfg: ModelConfig, rcfg: RunConfig) -> Model:
             decode_state=DecodeState(kind="encdec",
                                      window_step=_window_from_step(ed_step)),
         )
+    if cfg.layer_types:
+        return _build_hybrid(cfg, rcfg)
     # right-padded batched prefill is exact only when pad tokens cannot leak
     # into real lanes: full causal attention, no recurrent state, no frontend.
     # MoE is excluded too — pad tokens compete for (and resize) expert
@@ -198,6 +201,38 @@ def build_model(cfg: ModelConfig, rcfg: RunConfig) -> Model:
                 (lambda p, c, t, bt: LM.lm_decode_window_pool(
                     cfg, p, c, t, bt, rcfg))
                 if pool_ok else None),
+        ),
+    )
+
+
+def _build_hybrid(cfg: ModelConfig, rcfg: RunConfig) -> Model:
+    """granitemoehybrid (per-layer Mamba2 / attention mixers, each with its
+    own expert FFN; :mod:`repro.models.hybrid`).  Its state is recurrent,
+    so the dense-lane layout goes to the ``RecurrentBackend``; the pool
+    keeps the attention K/V in blocks and one Mamba2 state slot per lane.
+    Right-padded batched prefill is exact for it (pads carry dt = 0 and
+    the expert layer is dropless).  No pooled verify window: the
+    speculative rollback cannot retreat recurrent state in a pool."""
+    pdt = jnp.dtype(rcfg.param_dtype)
+    cdt = jnp.dtype(rcfg.compute_dtype)
+    step = lambda p, c, t: HY.hybrid_decode_step(cfg, p, c, t, rcfg)
+    return Model(
+        cfg=cfg, rcfg=rcfg,
+        init=lambda key: HY.init_hybrid(cfg, key, pdt),
+        loss=lambda p, b: HY.hybrid_loss(cfg, p, b, rcfg),
+        prefill=lambda p, b, ml: HY.hybrid_prefill(cfg, p, b, rcfg, ml),
+        decode_step=step,
+        init_cache=lambda bsz, ml: HY.init_cache(cfg, bsz, ml, cdt),
+        input_specs=lambda s: HY.input_specs(cfg, s, rcfg),
+        decode_state=DecodeState(
+            kind="recurrent",
+            batched_prefill=lambda p, b, ln, ml: HY.hybrid_prefill(
+                cfg, p, b, rcfg, ml, lengths=ln),
+            pool_init=lambda nl, nb, bs: HY.init_pool_cache(cfg, nl, nb, bs,
+                                                            cdt),
+            pool_step=lambda p, c, t, bt: HY.hybrid_decode_step_pool(
+                cfg, p, c, t, bt, rcfg),
+            window_step=_window_from_step(step),
         ),
     )
 
@@ -246,9 +281,14 @@ def stage_model(model: Model, lo: int, hi: int) -> Model:
     """
     cfg, rcfg = model.cfg, model.rcfg
     if not stage_eligible(cfg):
+        why = ("its recurrent state (Mamba2 / RWKV) lives in the serving "
+               "pool as one slot per lane, which the stage protocol does not "
+               "carry across a cut" if cfg.rwkv or cfg.family in ("ssm",
+                                                                  "hybrid")
+               else "its layers are not self-contained")
         raise ValueError(
             f"family {cfg.family!r} (rwkv={cfg.rwkv}) cannot be layer-split "
-            f"into serving stages")
+            f"into serving stages: {why}")
     if not (0 <= lo < hi <= cfg.n_layers):
         raise ValueError(f"bad stage range [{lo}, {hi}) for "
                          f"{cfg.n_layers} layers")

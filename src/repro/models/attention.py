@@ -91,12 +91,12 @@ def attention(params: dict, cfg: ModelConfig, x: jax.Array, *,
     """Self-attention over full sequence (training / prefill)."""
     b, t, _ = x.shape
     q, k, v = _proj_qkv(params, x, x, cfg)
-    if use_rope:
+    if use_rope and cfg.position_embedding == "rope":
         if positions is None:
             positions = jnp.arange(t)[None, :]
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-    scale = cfg.head_dim ** -0.5
+    scale = cfg.attn_scale
     local_chunk = cfg.chunk_size if cfg.attention == "chunked_local" else 0
     if use_kernels:
         from repro.kernels import ops as kops
@@ -125,7 +125,7 @@ def cross_attention(params: dict, cfg: ModelConfig, x: jax.Array,
     b, t, _ = x.shape
     q, k, v = _proj_qkv(params, x, enc_out, cfg)
     nrep = cfg.n_heads // cfg.n_kv_heads
-    out = sdpa(q, _repeat_kv(k, nrep), _repeat_kv(v, nrep), None, cfg.head_dim ** -0.5)
+    out = sdpa(q, _repeat_kv(k, nrep), _repeat_kv(v, nrep), None, cfg.attn_scale)
     return out.reshape(b, t, cfg.q_dim) @ params["wo"]
 
 
@@ -159,7 +159,7 @@ def decode_attn(params: dict, cfg: ModelConfig, x: jax.Array,
     b = x.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     q, k, v = _proj_qkv(params, x, x, cfg)
-    if use_rope:
+    if use_rope and cfg.position_embedding == "rope":
         q = apply_rope(q, pos[:, None], cfg.rope_theta)
         k = apply_rope(k, pos[:, None], cfg.rope_theta)
     span = ck.shape[1]
@@ -179,13 +179,13 @@ def decode_attn(params: dict, cfg: ModelConfig, x: jax.Array,
     if use_kernels:
         from repro.kernels import ops as kops
         out = kops.decode_attention(q, ck.astype(q.dtype), cv.astype(q.dtype),
-                                    valid, scale=cfg.head_dim ** -0.5)
+                                    valid, scale=cfg.attn_scale)
     else:
         nrep = cfg.n_heads // cfg.n_kv_heads
         kk = _repeat_kv(ck.astype(q.dtype), nrep)
         vv = _repeat_kv(cv.astype(q.dtype), nrep)
         mask = valid[:, None, None, :]                # -> (B,H,1,span)
-        out = sdpa(q, kk, vv, mask, cfg.head_dim ** -0.5)
+        out = sdpa(q, kk, vv, mask, cfg.attn_scale)
     return out.reshape(b, 1, cfg.q_dim) @ params["wo"], ck, cv
 
 
@@ -211,7 +211,7 @@ def decode_attn_paged(params: dict, cfg: ModelConfig, x: jax.Array,
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     with jax.named_scope("attn"):
         q, k, v = _proj_qkv(params, x, x, cfg)
-        if use_rope:
+        if use_rope and cfg.position_embedding == "rope":
             q = apply_rope(q, pos[:, None], cfg.rope_theta)
             k = apply_rope(k, pos[:, None], cfg.rope_theta)
     p_eff = jnp.minimum(pos, span_l - 1)          # saturate like the dense path
@@ -222,26 +222,33 @@ def decode_attn_paged(params: dict, cfg: ModelConfig, x: jax.Array,
             k[:, 0].astype(kp.dtype)).reshape(kp.shape)
         vp = vp.reshape((nb * bs,) + vp.shape[2:]).at[dest].set(
             v[:, 0].astype(vp.dtype)).reshape(vp.shape)
-    scale = cfg.head_dim ** -0.5
     with jax.named_scope("attn"):
-        if use_kernels:
-            from repro.kernels import ops as kops
-            out = kops.paged_decode_attention(q, kp.astype(q.dtype),
-                                              vp.astype(q.dtype), block_tables,
-                                              p_eff, scale=scale)
-        else:
-            # gather reference: materialise each lane's logical KV view
-            ck = kp[block_tables].reshape(b, span_l, cfg.n_kv_heads,
-                                          cfg.head_dim)
-            cv = vp[block_tables].reshape(b, span_l, cfg.n_kv_heads,
-                                          cfg.head_dim)
-            valid = jnp.arange(span_l)[None, :] <= p_eff[:, None]  # (B, span_l)
-            nrep = cfg.n_heads // cfg.n_kv_heads
-            kk = _repeat_kv(ck.astype(q.dtype), nrep)
-            vv = _repeat_kv(cv.astype(q.dtype), nrep)
-            out = sdpa(q, kk, vv, valid[:, None, None, :], scale)
+        out = paged_attend(cfg, q, kp, vp, block_tables, p_eff,
+                           use_kernels=use_kernels)
         out = out.reshape(b, 1, cfg.q_dim) @ params["wo"]
     return out, kp, vp
+
+
+def paged_attend(cfg: ModelConfig, q: jax.Array, kp: jax.Array,
+                 vp: jax.Array, block_tables: jax.Array, p_eff: jax.Array, *,
+                 use_kernels: bool = False) -> jax.Array:
+    """One query per lane (B,1,H,Dh) against one layer's block pool, lane
+    positions 0..p_eff; (B,1,H,Dh) out.  The paged kernel, or the gather
+    reference that materialises each lane's logical KV view."""
+    b = q.shape[0]
+    span_l = block_tables.shape[1] * kp.shape[1]
+    if use_kernels:
+        from repro.kernels import ops as kops
+        return kops.paged_decode_attention(q, kp.astype(q.dtype),
+                                           vp.astype(q.dtype), block_tables,
+                                           p_eff, scale=cfg.attn_scale)
+    ck = kp[block_tables].reshape(b, span_l, cfg.n_kv_heads, cfg.head_dim)
+    cv = vp[block_tables].reshape(b, span_l, cfg.n_kv_heads, cfg.head_dim)
+    valid = jnp.arange(span_l)[None, :] <= p_eff[:, None]  # (B, span_l)
+    nrep = cfg.n_heads // cfg.n_kv_heads
+    kk = _repeat_kv(ck.astype(q.dtype), nrep)
+    vv = _repeat_kv(cv.astype(q.dtype), nrep)
+    return sdpa(q, kk, vv, valid[:, None, None, :], cfg.attn_scale)
 
 
 def prefill_attn(params: dict, cfg: ModelConfig, x: jax.Array,
@@ -252,11 +259,11 @@ def prefill_attn(params: dict, cfg: ModelConfig, x: jax.Array,
 
     Returns (out (B,T,D), ck (B,span,KVH,Dh), cv)."""
     b, t, _ = x.shape
-    scale = cfg.head_dim ** -0.5
+    scale = cfg.attn_scale
     local_chunk = cfg.chunk_size if cfg.attention == "chunked_local" else 0
     with jax.named_scope("attn"):
         q, k, v = _proj_qkv(params, x, x, cfg)
-        if use_rope:
+        if use_rope and cfg.position_embedding == "rope":
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
         if use_kernels:

@@ -9,6 +9,10 @@ stacked unit:
     block_prefill(cfg, bp, x, idx, positions, span, uk) -> (x, cache_layer)
     block_decode(cfg, bp, x, cache_layer, pos, idx, uk) -> (x, cache_layer)
 
+Expert layers: training (``block_train``) keeps GShard capacity dropping;
+every serving path (prefill and both decode forms) runs the dropless
+layer, so a token's output never depends on what else is in the batch.
+
 zamba2's SHARED attention block (one set of weights fired every
 ``attn_every`` layers) is handled by the assembly layer (`repro.models.lm`)
 with its own compact ``n_attn``-slot cache — per-layer stacking would waste
@@ -161,7 +165,7 @@ def block_prefill(cfg: ModelConfig, bp: dict, x: jax.Array, idx,
     with jax.named_scope("mlp"):
         h = rmsnorm(bp["ln2"], x)
         if cfg.n_experts:
-            y, _ = moe_mod.apply_moe(bp["moe"], cfg, h)
+            y, _ = moe_mod.apply_moe_dropless(bp["moe"], cfg, h)
             x = x + y
         else:
             x = x + apply_mlp(bp["mlp"], h, cfg.act)
@@ -195,7 +199,7 @@ def block_decode(cfg: ModelConfig, bp: dict, x: jax.Array, cache: dict,
     x = x + h
     h = rmsnorm(bp["ln2"], x)
     if cfg.n_experts:
-        y, _ = moe_mod.apply_moe(bp["moe"], cfg, h, group_size=max(1, x.shape[0]))
+        y, _ = moe_mod.apply_moe_dropless(bp["moe"], cfg, h)
         x = x + y
     else:
         x = x + apply_mlp(bp["mlp"], h, cfg.act)
@@ -215,8 +219,7 @@ def block_decode_paged(cfg: ModelConfig, bp: dict, x: jax.Array,
     with jax.named_scope("mlp"):
         h = rmsnorm(bp["ln2"], x)
         if cfg.n_experts:
-            y, _ = moe_mod.apply_moe(bp["moe"], cfg, h,
-                                     group_size=max(1, x.shape[0]))
+            y, _ = moe_mod.apply_moe_dropless(bp["moe"], cfg, h)
             x = x + y
         else:
             x = x + apply_mlp(bp["mlp"], h, cfg.act)

@@ -98,33 +98,57 @@ def mamba2_scan_ref(x_h, dt, A, B, C, D, ssm_state=None):
 
 def apply_mamba2(params: dict, cfg: ModelConfig, x: jax.Array, *,
                  use_kernels: bool = False,
-                 state: Optional[dict] = None) -> Tuple[jax.Array, Optional[dict]]:
+                 state: Optional[dict] = None,
+                 lengths: Optional[jax.Array] = None) -> Tuple[jax.Array, dict]:
     """Full-sequence (train/prefill) when state is None; single/multi-token
-    stateful otherwise.  x: (B,T,D)."""
+    stateful otherwise.  x: (B,T,D).  Returns (out, {"conv", "ssm"}): the
+    conv tail (last K-1 conv inputs) and the SSM state after the sequence.
+
+    ``lengths`` (B,) marks a right-padded batch (prefill from a zero
+    state): ``dt`` is 0 at every pad position, so the state passes through
+    pads unchanged (decay e^0 = 1, update 0), and the conv tail is
+    gathered at each lane's true length.  Profile scopes: ``in_proj``,
+    ``conv``, ``ssm_state``, ``out_proj``."""
     d_in, nheads, conv_dim = dims(cfg)
     n = N_GROUPS * cfg.ssm_state
-    proj = x @ params["in_proj"]
-    z, xbc, dt = _split_proj(cfg, proj)
-    conv_state = None if state is None else state["conv"]
-    xbc, new_conv = _causal_conv(xbc, params["conv_w"], params["conv_b"], conv_state)
-    xh, B, C = jnp.split(xbc, [d_in, d_in + n], axis=-1)
     bsz, t = x.shape[:2]
-    xh = xh.reshape(bsz, t, nheads, cfg.ssm_headdim)
-    A = -jnp.exp(params["A_log"])
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
-    ssm_state = None if state is None else state["ssm"]
-    if use_kernels and state is None:
-        from repro.kernels import ops as kops
-        y, S = kops.mamba2_ssd(xh, dt, A, B, C, params["D"])
-    else:
-        y, S = mamba2_scan_ref(xh, dt, A, B, C, params["D"], ssm_state)
-    y = y.reshape(bsz, t, d_in)
-    y = rmsnorm({"scale": params["norm_scale"]}, y * jax.nn.silu(z.astype(y.dtype)))
-    out = y @ params["out_proj"]
-    new_state = None if state is None else {"conv": new_conv, "ssm": S}
-    if state is not None:
-        new_state = {"conv": new_conv.astype(state["conv"].dtype), "ssm": S}
-    return out, new_state
+    with jax.named_scope("in_proj"):
+        proj = x @ params["in_proj"]
+        z, xbc, dt = _split_proj(cfg, proj)
+    with jax.named_scope("conv"):
+        conv_state = None if state is None else state["conv"]
+        xbc_in = xbc
+        xbc, new_conv = _causal_conv(xbc, params["conv_w"], params["conv_b"],
+                                     conv_state)
+        if lengths is not None:
+            k = cfg.ssm_conv
+            xin = jnp.concatenate(
+                [jnp.zeros((bsz, k - 1, conv_dim), xbc_in.dtype), xbc_in],
+                axis=1)                                    # (B, K-1+T, C)
+            at = lengths[:, None] + jnp.arange(k - 1)[None, :]
+            new_conv = jnp.take_along_axis(xin, at[:, :, None], axis=1)
+    with jax.named_scope("ssm_state"):
+        xh, B, C = jnp.split(xbc, [d_in, d_in + n], axis=-1)
+        xh = xh.reshape(bsz, t, nheads, cfg.ssm_headdim)
+        A = -jnp.exp(params["A_log"])
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
+        if lengths is not None:
+            dt = jnp.where(jnp.arange(t)[None, :, None] < lengths[:, None, None],
+                           dt, 0.0)
+        ssm_state = None if state is None else state["ssm"]
+        if use_kernels and state is None:
+            from repro.kernels import ops as kops
+            y, S = kops.mamba2_ssd(xh, dt, A, B, C, params["D"],
+                                   chunk=cfg.ssm_chunk)
+        else:
+            y, S = mamba2_scan_ref(xh, dt, A, B, C, params["D"], ssm_state)
+    with jax.named_scope("out_proj"):
+        y = y.reshape(bsz, t, d_in)
+        y = rmsnorm({"scale": params["norm_scale"]},
+                    y * jax.nn.silu(z.astype(y.dtype)))
+        out = y @ params["out_proj"]
+    conv_dt = proj.dtype if state is None else state["conv"].dtype
+    return out, {"conv": new_conv.astype(conv_dt), "ssm": S}
 
 
 def init_mamba2_state(cfg: ModelConfig, batch: int, n_layers: int, dtype) -> dict:

@@ -191,12 +191,16 @@ _DENSE_ADD_POS = jax.jit(_dense_add_pos, donate_argnums=0)
 _DENSE_SET_POS = jax.jit(_dense_set_pos, donate_argnums=0)
 
 
-def _pool_paste(cache, src_layers, src_lane, flat_idx, dst_slot, length):
+def _pool_paste(cache, src, src_lane, flat_idx, dst_slot, length):
     """Scatter lane ``src_lane`` of a prefill cache into a lane's
     allocated pool blocks.  ``flat_idx`` (width,) maps prefill positions
     to flattened pool slots; positions past the real context — and
     positions already covered by SHARED cache blocks, which must never be
-    rewritten — point at the sink."""
+    rewritten — point at the sink.  A pool with per-lane recurrent state
+    (``cache["state"]``, lane axis second) gets the lane's state copied
+    into slot ``dst_slot`` whole."""
+    src_layers = src["layers"]
+
     def fix(pool, src):
         nl = pool.shape[0]
         flat = pool.reshape((nl, -1) + pool.shape[3:])
@@ -208,22 +212,33 @@ def _pool_paste(cache, src_layers, src_lane, flat_idx, dst_slot, length):
         return flat.reshape(pool.shape)
     layers = {"k": fix(cache["layers"]["k"], src_layers["k"]),
               "v": fix(cache["layers"]["v"], src_layers["v"])}
-    pos = cache["pos"].at[dst_slot].set(length)
-    return {"layers": layers, "pos": pos}
+    out = {**cache, "layers": layers,
+           "pos": cache["pos"].at[dst_slot].set(length)}
+    if "state" in cache:
+        def lane(dst, s):
+            piece = jax.lax.dynamic_index_in_dim(s, src_lane, 1, True)
+            return jax.lax.dynamic_update_index_in_dim(
+                dst, piece.astype(dst.dtype), dst_slot, 1)
+        out["state"] = jax.tree.map(lane, cache["state"], src["state"])
+    return out
 
 
 def _pool_set_pos(cache, slot, val):
-    return {"layers": cache["layers"],
-            "pos": cache["pos"].at[slot].set(val)}
+    return {**cache, "pos": cache["pos"].at[slot].set(val)}
 
 
 def _pool_cow_copy(cache, src, dst):
     """Duplicate one pool block (all layers, K and V) dst <- src."""
     def fix(pool):
         return pool.at[:, dst].set(pool[:, src])
-    return {"layers": {"k": fix(cache["layers"]["k"]),
-                       "v": fix(cache["layers"]["v"])},
-            "pos": cache["pos"]}
+    return {**cache, "layers": {"k": fix(cache["layers"]["k"]),
+                                "v": fix(cache["layers"]["v"])}}
+
+
+def _pool_clear_lane(cache, slot):
+    """Zero one lane's recurrent state slot (release / preemption)."""
+    return {**cache, "state": jax.tree.map(
+        lambda a: a.at[:, slot].set(0), cache["state"])}
 
 
 # pool-layout helpers are model-independent pure functions: one wrapper
@@ -231,6 +246,7 @@ def _pool_cow_copy(cache, src, dst):
 _POOL_PASTE = jax.jit(_pool_paste, donate_argnums=0)
 _POOL_SET_POS = jax.jit(_pool_set_pos, donate_argnums=0)
 _POOL_COW_COPY = jax.jit(_pool_cow_copy, donate_argnums=0)
+_POOL_CLEAR_LANE = jax.jit(_pool_clear_lane, donate_argnums=0)
 
 
 class CacheBackend:
@@ -249,6 +265,7 @@ class CacheBackend:
         self.max_len = max_len
 
     # -- gauges (zeros unless the layout tracks them) -------------------
+    lane_state_bytes = 0
     n_blocks = 0
     blocks_in_use = 0
     peak_blocks = 0
@@ -295,6 +312,9 @@ class CacheBackend:
 
     def reset_counters(self) -> None:
         pass
+
+    def expert_load_mean(self) -> float:
+        return 0.0
 
 
 class DenseBackend(CacheBackend):
@@ -513,10 +533,22 @@ class PagedBackend(CacheBackend):
                  watermark_frac: float = 0.0, prefix_cache: bool = False):
         super().__init__(model, n_lanes, max_len)
         ds = model.decode_state
+        # a hybrid's pool holds one recurrent state slot per lane beside
+        # the attention blocks: no prefix of it can be shared, and nothing
+        # written to it can be taken back
+        self.recurrent = ds.kind == "recurrent"
+        if prefix_cache and self.recurrent:
+            raise ValueError(
+                "prefix_cache is refused for a pool with recurrent state: a "
+                "lane's Mamba2 state after a prefix cannot be shared through "
+                "cached K/V blocks, so a prefix hit would skip the state's "
+                "prefill and serve wrong tokens")
         self.blocks = BlockManager(kv_blocks, block_size, watermark_frac)
         self.prefix_cache = prefix_cache
         self.max_blocks_per_lane = -(-max_len // block_size)
         self.cache = ds.pool_init(n_lanes, kv_blocks, block_size)
+        self._decode_steps = 0
+        self._held_base = 0
         self.block_tables = np.zeros(
             (n_lanes, self.max_blocks_per_lane), np.int32)
         self._lane_blocks: List[List[int]] = [[] for _ in range(n_lanes)]
@@ -572,6 +604,31 @@ class PagedBackend(CacheBackend):
         bm.shared_peak = bm.shared_now
         bm.cow_splits = 0
         bm.evictions = 0
+        self._decode_steps = 0
+        self._held_base = self._held_slots()
+
+    def _held_slots(self) -> int:
+        counters = self.cache.get("counters")
+        return int(counters["held_slots"]) if counters is not None else 0
+
+    def expert_load_mean(self) -> float:
+        """Token-slots of active lanes routed to the held experts, per held
+        expert, per expert layer and decode step since the last reset (a
+        device counter, read here once)."""
+        cfg = self.model.cfg
+        if "counters" not in self.cache or not self._decode_steps:
+            return 0.0
+        per = self._decode_steps * cfg.n_layers * cfg.n_held_experts
+        return (self._held_slots() - self._held_base) / per
+
+    @property
+    def lane_state_bytes(self) -> int:
+        """Bytes of one lane's recurrent state slot (0 without one)."""
+        st = self.cache.get("state")
+        if st is None:
+            return 0
+        return int(sum(a.size // a.shape[1] * a.dtype.itemsize
+                       for a in jax.tree.leaves(st)))
 
     def cached_prefix_tokens(self, tokens) -> int:
         if not self.prefix_cache or tokens is None:
@@ -658,7 +715,7 @@ class PagedBackend(CacheBackend):
     def prefill_paste(self, slot: int, group_cache, src_lane: int,
                       n_ctx: int, width: int, res: Reservation) -> None:
         flat = self._flat_idx(res.blocks, res.n_cached, n_ctx, width)
-        self.cache = self._paste(self.cache, group_cache["layers"],
+        self.cache = self._paste(self.cache, group_cache,
                                  jnp.int32(src_lane), jnp.asarray(flat),
                                  jnp.int32(slot), jnp.int32(n_ctx))
         self._install(slot, res.blocks, n_ctx)
@@ -716,15 +773,24 @@ class PagedBackend(CacheBackend):
                                           jnp.asarray(tokens),
                                           jnp.asarray(self.block_tables))
         self._lane_pos[active] += 1
+        self._decode_steps += 1
         return logits
 
     # -- speculative verify plumbing -----------------------------------
+    def _refuse_recurrent(self, what: str) -> None:
+        if self.recurrent:
+            raise ValueError(
+                f"{what} is refused for a pool with recurrent state: the "
+                f"verify window advances each lane's Mamba2 state, which a "
+                f"rollback cannot retreat as it retreats K/V positions")
+
     def append_tokens(self, slot: int, toks: Sequence[int]) -> bool:
         """Reserve write capacity for ``len(toks)`` consecutive positions:
         run the single-position ``prepare_lane`` (grow / COW-split /
         uncache) once per position, crossing block boundaries as needed.
         All-or-nothing: on exhaustion the position is restored and the
         engine preempts a victim and retries."""
+        self._refuse_recurrent("speculative verify")
         pos0 = int(self._lane_pos[slot])
         for i in range(len(toks)):
             self._lane_pos[slot] = pos0 + i
@@ -735,6 +801,7 @@ class PagedBackend(CacheBackend):
         return True
 
     def verify_step(self, params, tokens: np.ndarray, active: np.ndarray):
+        self._refuse_recurrent("speculative verify")
         if self._pool_window is None:
             self._pool_window = _pool_window_jit(self.model.decode_state)
         w = tokens.shape[1]
@@ -753,6 +820,7 @@ class PagedBackend(CacheBackend):
         COW-private blocks), never a shared/cached prefix block."""
         if n <= 0:
             return
+        self._refuse_recurrent("rollback")
         bm = self.blocks
         new_pos = max(0, int(self._lane_pos[slot]) - n)
         self._lane_pos[slot] = new_pos
@@ -787,6 +855,8 @@ class PagedBackend(CacheBackend):
                 n_valid = min(int(self._lane_pos[slot]), len(tokens))
                 self.blocks.register(blocks, tokens[:n_valid])
             self.blocks.release(blocks)
+            if self.recurrent:
+                self.cache = _POOL_CLEAR_LANE(self.cache, jnp.int32(slot))
         self._lane_blocks[slot] = []
         self.block_tables[slot, :] = 0
         self._lane_pos[slot] = 0

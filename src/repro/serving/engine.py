@@ -692,4 +692,6 @@ class ServeEngine:
             kv_blocks_peak=self.backend.peak_blocks,
             kv_shared_blocks_peak=self.backend.shared_blocks_peak,
             cow_splits=self.backend.cow_splits,
-            cache_evictions=self.backend.cache_evictions)
+            cache_evictions=self.backend.cache_evictions,
+            expert_load_mean=self.backend.expert_load_mean(),
+            lane_state_bytes=self.backend.lane_state_bytes)

@@ -122,6 +122,11 @@ class EngineSnapshot:
     step_max_phases: Dict[str, float] = dataclasses.field(
         default_factory=dict)          # its seconds by direct child span
     prefill_wait: LatencyStats = LatencyStats.of([])  # round start -> 1st tok
+    # expert layers and recurrent state (zero where the model has none)
+    expert_load_mean: float = 0.0      # held-expert token-slots / expert /
+    #                                    layer / decode step (active lanes)
+    recurrent_state_bytes: float = 0.0  # lane state held by busy lanes,
+    #                                     step-weighted mean (bytes)
 
     def as_dict(self) -> Dict:
         return dataclasses.asdict(self)
@@ -385,7 +390,8 @@ class MetricsCollector:
     def snapshot(self, *, queue_depth_now: int = 0, rejected: int = 0,
                  expired: int = 0, kv_blocks_peak: int = 0,
                  kv_shared_blocks_peak: int = 0, cow_splits: int = 0,
-                 cache_evictions: int = 0) -> EngineSnapshot:
+                 cache_evictions: int = 0, expert_load_mean: float = 0.0,
+                 lane_state_bytes: int = 0) -> EngineSnapshot:
         wall = 0.0
         if self._t_first is not None and self._t_last is not None:
             wall = max(self._t_last - self._t_first, 0.0)
@@ -439,4 +445,7 @@ class MetricsCollector:
             step_max_s=self.step_max_s,
             step_max_phases=dict(sorted(self.step_max_phases.items())),
             prefill_wait=LatencyStats.of(self.prefill_wait),
+            expert_load_mean=expert_load_mean,
+            recurrent_state_bytes=(lane_state_bytes * self._busy_sum
+                                   / self.steps if self.steps else 0.0),
         )

@@ -27,6 +27,7 @@ from __future__ import annotations
 import io
 import json
 import struct
+import sys
 import zlib
 from typing import Any, BinaryIO, List, Tuple
 
@@ -94,6 +95,8 @@ def encode_tensor(arr: Any, out: BinaryIO) -> int:
 
 
 def _read_exact(src: BinaryIO, n: int) -> bytes:
+    if not 0 <= n <= sys.maxsize:      # a corrupted length or shape field
+        raise WireError(f"bad frame length {n}")
     buf = src.read(n)
     if len(buf) != n:
         raise WireError(f"truncated frame: wanted {n} bytes, got {len(buf)}")

@@ -11,13 +11,15 @@ PUBLISHED = {
     "whisper-small": 0.244, "zamba2-7b": 7.0, "mistral-nemo-12b": 12.2,
     "yi-34b": 34.4, "granite-8b": 8.1, "command-r-35b": 35.0,
     "llama4-scout-17b-a16e": 109.0, "grok-1-314b": 314.0,
-    "rwkv6-1.6b": 1.6, "internvl2-1b": 0.50,
+    "rwkv6-1.6b": 1.6, "internvl2-1b": 0.50, "granite-4.0-h-small": 32.2,
 }
 ACTIVE = {"llama4-scout-17b-a16e": 17.0, "grok-1-314b": 86.0}
 
 
 def test_ten_archs():
-    assert len(ARCH_IDS) == 10
+    # the ten assigned architectures and granite-4.0-h-small, served on
+    # the chip benchmark
+    assert len(ARCH_IDS) == 11
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -44,7 +46,8 @@ def test_reduced_preserves_structure(arch):
 def test_long_500k_applicability():
     runs = {a for a in ARCH_IDS
             if shape_applicable(get_config(a), SHAPES["long_500k"])[0]}
-    assert runs == {"zamba2-7b", "rwkv6-1.6b", "llama4-scout-17b-a16e"}
+    assert runs == {"zamba2-7b", "rwkv6-1.6b", "llama4-scout-17b-a16e",
+                    "granite-4.0-h-small"}
 
 
 def test_shapes():
